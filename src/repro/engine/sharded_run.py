@@ -40,7 +40,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.engine import batched_run as br
-from repro.parallel.compat import shard_map
 from repro.parallel.sharding import SNN_SERVE_RULES, ShardingRules
 
 
@@ -113,9 +112,9 @@ def _sharded_forward(mesh: Mesh, spec: PartitionSpec, donate: bool):
     def fwd(packed, spikes, max_events):
         br._bump_trace("sharded", donated=donate)
         body = functools.partial(br._forward_impl, max_events=max_events)
-        mapped = shard_map(body, mesh=mesh,
-                           in_specs=(PartitionSpec(), spec),
-                           out_specs=spec, check_rep=False)
+        mapped = jax.shard_map(body, mesh=mesh,
+                               in_specs=(PartitionSpec(), spec),
+                               out_specs=spec, check_vma=False)
         return mapped(packed, spikes)
 
     kwargs = dict(static_argnames=("max_events",))

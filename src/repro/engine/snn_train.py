@@ -52,7 +52,6 @@ from repro.engine.sharded_run import snn_serve_mesh
 from repro.engine.train_loop import (TrainLoopConfig, init_train_state,
                                      resume_or_init, train_loop)
 from repro.optim.adamw import AdamWConfig, adamw_update
-from repro.parallel.compat import shard_map
 from repro.parallel.sharding import SNN_TRAIN_RULES, ShardingRules
 from repro.snn import conv as _conv
 from repro.snn import mlp as _mlp
@@ -285,10 +284,10 @@ def make_snn_train_step(model: SNNModel, cfg, opt_cfg: AdamWConfig, *,
                 local = chunked(params, sp, lb, k // n_split)
                 return jax.lax.all_gather(local, axes, tiled=True)
 
-            stacked = shard_map(
+            stacked = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(PartitionSpec(), spikes_spec, labels_spec),
-                out_specs=PartitionSpec(), check_rep=False)(
+                out_specs=PartitionSpec(), check_vma=False)(
                     state["params"], spikes, labels)
         else:
             stacked = chunked(state["params"], spikes, labels, k)

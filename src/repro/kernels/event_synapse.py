@@ -6,8 +6,9 @@ event list (the software MEM_E) gathers weight rows from the VMEM-resident
 weight tile and accumulates membrane currents.
 
 Tiling: grid = (B, n_dest / BLOCK_D).  Each program instance owns one
-(sample, dest-block) pair; the full event list of that sample and the
-[n_src, BLOCK_D] weight tile are in VMEM.  The inner fori_loop plays the role
+(sample, dest-block) pair; the full event list of that sample is in SMEM,
+read one scalar index per event, and the [n_src, BLOCK_D] weight tile is in
+VMEM, read one row per event.  The inner fori_loop plays the role
 of the controller's per-event dispatch cycles; BLOCK_D is the vectorized lane
 dimension — the "engine" axis onto which virtual neurons are packed.
 
@@ -23,26 +24,57 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BLOCK_D = 256
+LANES = 128     # TPU vreg lane width: a lane block is this wide or whole
+
+
+def _lane_block(n: int, want: int) -> int:
+    """Width of a last-axis block the TPU tiling accepts: the whole axis, or
+    the smallest multiple of 128 lanes >= ``want`` that divides it.  The
+    width changes which grid step computes a column, never its value."""
+    if want >= n:
+        return n
+    b = -(-max(want, 1) // LANES) * LANES
+    while b < n and n % b:
+        b += LANES
+    return min(b, n)
+
+
+def _accumulate(events_ref, read_row, n_planes: int, width: int):
+    """The per-event dispatch loop shared by both kernels: for each MEM_E
+    slot in order, read the event's source index from SMEM (a scalar read)
+    and add the weight row ``read_row(plane, idx)`` ([1, width] f32) of every
+    plane — one f32 add per event per column, in ascending source order, and
+    an exact ``0.0`` for the ``-1`` padding slots."""
+    n_events = events_ref.shape[1]
+
+    def body(e, accs):
+        idx = events_ref[0, e]
+        valid = idx >= 0
+        safe = jnp.where(valid, idx, 0)
+        return tuple(acc + jnp.where(valid, read_row(p, safe), 0.0)
+                     for p, acc in enumerate(accs))
+
+    zero = jnp.zeros((1, width), jnp.float32)
+    return jax.lax.fori_loop(0, n_events, body, (zero,) * n_planes)
 
 
 def _event_synapse_kernel(events_ref, weights_ref, out_ref):
-    """events [1, E] int32; weights [n_src, BD] f32; out [1, BD] f32."""
-    events = events_ref[0, :]                       # [E]
-    n_events = events.shape[0]
-    bd = out_ref.shape[1]
+    """events [1, E] i32 (SMEM); weights [n_src, BD] f32; out [1, BD] f32."""
+    (acc,) = _accumulate(
+        events_ref, lambda _, idx: weights_ref[pl.ds(idx, 1), :], 1,
+        out_ref.shape[1])
+    out_ref[...] = acc
 
-    def body(e, acc):
-        idx = events[e]
-        valid = idx >= 0
-        safe = jnp.where(valid, idx, 0)
-        row = pl.load(weights_ref, (pl.dslice(safe, 1), slice(None)))  # [1, BD]
-        return acc + jnp.where(valid, row[0], jnp.zeros((bd,), acc.dtype))
 
-    acc = jax.lax.fori_loop(0, n_events, body, jnp.zeros((bd,), out_ref.dtype))
-    out_ref[0, :] = acc
+def _event_spec(n_events: int) -> pl.BlockSpec:
+    """One row's event list per grid step, in SMEM so the loop reads each
+    index as a scalar: ``[rows, 1, E]`` blocked ``(None, 1, E)``."""
+    return pl.BlockSpec((None, 1, n_events), lambda i, j: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -58,66 +90,56 @@ def event_synapse(events: jax.Array, weights: jax.Array,
         # a zero-size grid still asks pallas for a (1, E) block slice of the
         # (0, E) events operand, so short-circuit before the kernel
         return jnp.zeros((b, n_dest), weights.dtype)
-    bd = min(block_d, n_dest)
-    assert n_dest % bd == 0, f"n_dest={n_dest} not divisible by block_d={bd}"
-    grid = (b, n_dest // bd)
-    return pl.pallas_call(
+    bd = _lane_block(n_dest, block_d)
+    out = pl.pallas_call(
         _event_synapse_kernel,
-        grid=grid,
+        grid=(b, n_dest // bd),
         in_specs=[
-            pl.BlockSpec((1, events.shape[1]), lambda i, j: (i, 0)),
+            _event_spec(n_events),
             pl.BlockSpec((n_src, bd), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bd), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n_dest), weights.dtype),
+        out_specs=pl.BlockSpec((None, 1, bd), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, n_dest), weights.dtype),
         interpret=interpret,
-    )(events, weights)
+    )(events.reshape(b, 1, n_events), weights)
+    return out.reshape(b, n_dest)
 
 
 def _event_synapse_packed_kernel(events_ref, packed_ref, scale_ref, out_ref,
-                                 *, bits: int):
-    """events [1, E] i32; packed [n_src, BDB] int8 (sign-magnitude lanes);
-    scale [1, 1] f32; out [1, BD] f32 with ``BD = BDB * 8/bits``.
+                                 tile_ref, *, bits: int):
+    """events [1, E] i32 (SMEM); packed [n_src, BDB] int8 (sign-magnitude
+    lanes); scale [1, 1] f32 (SMEM); out [L, BDB] f32 with ``L = 8/bits``;
+    tile [L, n_src, BDB] f32 VMEM scratch.
 
     The weight tile arrives packed — ``bits/32`` of the f32 VMEM footprint,
     the twin of A-SYN storing sub-byte ladder words.  It is unpacked *once
-    per tile* before the event loop: split each byte into ``8/bits``
-    sub-words, then 1 sign + ``bits-1`` magnitude bits per word (the C2C
-    ladder's own format, quant.pack_signmag) and dequantize by the layer
-    scale — the DAC step at the ladder input.  The dequantized tile is a
-    loop operand (materialized at the fori_loop boundary), so the event loop
-    is gather+add only, with f32 partial sums bit-identical to the dense
-    kernel.  Keeping the multiply *inside* the loop is not an option: XLA
-    contracts mul+add into an FMA (even across optimization_barrier /
-    bitcast fences), skipping the intermediate rounding the dense path has.
+    per grid step*, before the event loop, into ``L`` planes: plane ``s``
+    holds sub-word ``s`` of every byte (destination ``byte * L + s``), 1
+    sign + ``bits-1`` magnitude bits (the C2C ladder's own format,
+    quant.pack_signmag), dequantized by the layer scale — the DAC step at
+    the ladder input — and stored to the scratch.  The event loop is then
+    gather+add only, with f32 partial sums bit-identical to the dense
+    kernel.  The multiply cannot move into the loop: XLA contracts mul+add
+    into an FMA, skipping the intermediate rounding the dense path has.
+    Planes, not an interleaved tile, because the TPU cannot merge lanes in
+    a reshape; the caller interleaves the ``[L, BDB]`` output instead.
     """
     ell = 8 // bits
     mask = (1 << bits) - 1
     mag_mask = (1 << (bits - 1)) - 1
-    events = events_ref[0, :]
-    n_events = events.shape[0]
-    n_src, bd_bytes = packed_ref.shape
-    bd = bd_bytes * ell
     scale = scale_ref[0, 0]
-
     r = packed_ref[...].astype(jnp.int32) & 0xFF  # undo int8 sign extension
-    lanes = jnp.stack([(r >> (s * bits)) & mask for s in range(ell)],
-                      axis=-1)                    # [n_src, BDB, L], dest-major
-    w = lanes.reshape(n_src, bd)
-    mag = w & mag_mask
-    sign = (w >> (bits - 1)) & 1
-    q = (mag - 2 * sign * mag).astype(jnp.float32)
-    w_tile = q * scale                            # fl32(q * scale), per elem
-
-    def body(e, acc):
-        idx = events[e]
-        valid = idx >= 0
-        safe = jnp.where(valid, idx, 0)
-        row = jax.lax.dynamic_slice_in_dim(w_tile, safe, 1, axis=0)  # [1, BD]
-        return acc + jnp.where(valid, row[0], jnp.zeros((bd,), acc.dtype))
-
-    acc = jax.lax.fori_loop(0, n_events, body, jnp.zeros((bd,), out_ref.dtype))
-    out_ref[0, :] = acc
+    for s in range(ell):
+        w = (r >> (s * bits)) & mask
+        mag = w & mag_mask
+        sign = (w >> (bits - 1)) & 1
+        q = (mag - 2 * sign * mag).astype(jnp.float32)
+        tile_ref[s] = q * scale                   # fl32(q * scale), per elem
+    accs = _accumulate(
+        events_ref, lambda s, idx: tile_ref[s, pl.ds(idx, 1), :], ell,
+        out_ref.shape[1])
+    for s, acc in enumerate(accs):
+        out_ref[pl.ds(s, 1), :] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_d", "interpret"))
@@ -135,8 +157,8 @@ def event_synapse_packed(events: jax.Array, packed_w: jax.Array,
 
     The VMEM weight tile per grid point shrinks proportionally to ``bits``
     (int8 codes at 8 bits are already 4x under f32; 4/2-bit lanes are 8x and
-    16x).  ``n_dest`` must be a multiple of ``8/bits`` so byte lanes align
-    with the dest tiling.
+    16x).  The grid tiles packed bytes, ``block_d / (8/bits)`` of them per
+    step, widened to a width the TPU tiling accepts.
     """
     ell = 8 // bits
     b, n_events = events.shape
@@ -145,23 +167,22 @@ def event_synapse_packed(events: jax.Array, packed_w: jax.Array,
     scale = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     if n_events == 0 or b == 0:
         return jnp.zeros((b, n_dest), jnp.float32)
-    bd = min(block_d, n_dest)
-    assert bd % ell == 0, \
-        f"block_d={bd} not a multiple of {ell} lanes/byte at {bits} bits"
-    assert n_dest % bd == 0, f"n_dest={n_dest} not divisible by block_d={bd}"
-    grid = (b, n_dest // bd)
-    return pl.pallas_call(
+    bdb = _lane_block(n_bytes, min(block_d, n_dest) // ell)
+    out = pl.pallas_call(
         functools.partial(_event_synapse_packed_kernel, bits=bits),
-        grid=grid,
+        grid=(b, n_bytes // bdb),
         in_specs=[
-            pl.BlockSpec((1, events.shape[1]), lambda i, j: (i, 0)),
-            pl.BlockSpec((n_src, bd // ell), lambda i, j: (0, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            _event_spec(n_events),
+            pl.BlockSpec((n_src, bdb), lambda i, j: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, bd), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n_dest), jnp.float32),
+        out_specs=pl.BlockSpec((None, ell, bdb), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, ell, n_bytes), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((ell, n_src, bdb), jnp.float32)],
         interpret=interpret,
-    )(events, packed_w, scale)
+    )(events.reshape(b, 1, n_events), packed_w, scale)
+    # plane s of byte j is destination j * ell + s
+    return out.transpose(0, 2, 1).reshape(b, n_dest)
 
 
 def events_from_spikes(spikes: jax.Array, max_events: int) -> jax.Array:

@@ -131,8 +131,7 @@ def lower_cell(arch: str, shape_name: str, mesh, rules_name: str = "base",
 
 
 def analyze(compiled, lowered, meta, n_devices: int) -> dict:
-    from repro.parallel.compat import compiled_cost_analysis
-    cost = compiled_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     # loop-aware re-analysis: cost_analysis() counts while bodies once (see
     # hlo_flops.py) — with scan-over-layers that undercounts by ~n_layers.

@@ -56,6 +56,7 @@ from repro.engine import (BucketPolicy, StreamServer,  # noqa: E402
 from repro.engine.chaos import (ARRIVAL_MODES, SCENARIOS,  # noqa: E402,F401
                                 run_scenario, synth_arrival_trace)
 from repro.engine.sharded_run import snn_serve_mesh  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def build_demo_model(kind: str, *, smoke: bool = False,
@@ -203,6 +204,7 @@ def main():
     args = ap.parse_args()
     donate = None if args.donate == "auto" else args.donate == "on"
     assert_spoof_applied(_SPOOFED)
+    enable_compile_cache()
 
     mesh = snn_serve_mesh(args.data)
     n_shards = mesh.size

@@ -460,6 +460,7 @@ class SpikeClient:
 
 def main():
     from repro.engine.sharded_run import snn_serve_mesh
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.serve_snn import build_demo_model, synth_requests
 
     ap = argparse.ArgumentParser()
@@ -491,6 +492,7 @@ def main():
     args = ap.parse_args()
     assert_spoof_applied(_SPOOFED)
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     from repro.core.noise import AnalogNoise  # after jax device spoof
 

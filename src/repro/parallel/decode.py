@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
 
 
 def _sp_attention_local(q, ck, cv, slot_pos, pos, window, axis: str):
@@ -82,7 +81,7 @@ def make_sp_attention(mesh: Mesh, axis: str = "model",
             return _sp_attention_local(q_l, ck_l, cv_l, slot_l, pos_l,
                                        window, axis)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(b_axes or None), P(b_axes or None, None, axis),
                       P(b_axes or None, None, axis), P(axis), P()),
@@ -122,7 +121,7 @@ def sp_cache_update(ck, cv, k_new, v_new, slot, mesh: Mesh,
 
     spec_c = P(b_axes or None, None, axis)
     spec_new = P(b_axes or None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_c, spec_c, spec_new, spec_new, P()),
         out_specs=(spec_c, spec_c),

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
 
 
 def _local_moe(xf, router, wg, wu, wd, *, n_experts: int, top_k: int,
@@ -136,7 +135,7 @@ def moe_ffn_sharded(x: jax.Array, lp: dict, cfg, mesh: Mesh,
         y, aux = body(x3.reshape(t_loc, d), router, wg, wu, wd)
         return y.reshape(x3.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         wrapper, mesh=mesh,
         in_specs=(xb, P(None, None), wg_spec, wg_spec, wd_spec),
         out_specs=(xb, P()),

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import pcast_varying, shard_map
 
 
 def pipeline_forward(layer_fn: Callable, params_stacked, x_microbatches,
@@ -49,8 +48,8 @@ def pipeline_forward(layer_fn: Callable, params_stacked, x_microbatches,
         outs = jnp.zeros_like(xs)
         # mark carries as device-varying (they diverge across stages after
         # the first ppermute) so scan's carry types stay consistent
-        buf = pcast_varying(buf, (stage_axis,))
-        outs = pcast_varying(outs, (stage_axis,))
+        buf = jax.lax.pcast(buf, (stage_axis,), to="varying")
+        outs = jax.lax.pcast(outs, (stage_axis,), to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -84,7 +83,7 @@ def pipeline_forward(layer_fn: Callable, params_stacked, x_microbatches,
             stage_axis)
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(stage_axis), params_stacked),
                   P()),

@@ -87,7 +87,6 @@ def test_hlo_flops_analyzer_exact_on_scan():
     out = _run("""
 import jax, jax.numpy as jnp
 from repro.launch.hlo_flops import analyze_hlo
-from repro.parallel.compat import compiled_cost_analysis
 
 def g(a, b):
     def body(x, _):
@@ -101,7 +100,7 @@ c = jax.jit(g).lower(a, b).compile()
 cost = analyze_hlo(c.as_text())
 expect = 11 * 2 * 64 * 128 * 128
 assert abs(cost.dot_flops - expect) / expect < 1e-6, (cost.dot_flops, expect)
-raw = compiled_cost_analysis(c)["flops"]
+raw = c.cost_analysis()["flops"]
 assert cost.dot_flops > 5 * raw
 print("OK")
 """, devices=1)
@@ -114,14 +113,13 @@ def test_collective_bytes_counted_with_loop_multiplier():
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.launch.hlo_flops import analyze_hlo
-from repro.parallel.compat import shard_map
 
 mesh = jax.make_mesh((4,), ("x",))
 
 def f(a):
     def body(x, _):
-        y = shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
-                      in_specs=P("x"), out_specs=P())(x)
+        y = jax.shard_map(lambda v: jax.lax.psum(v, "x"), mesh=mesh,
+                          in_specs=P("x"), out_specs=P())(x)
         return jnp.tanh(x * jnp.mean(y)), None
     x, _ = jax.lax.scan(body, a, None, length=5)
     return x
