@@ -111,8 +111,6 @@ def bench_scenarios(packed, mesh) -> list[dict]:
         rec1, rec2 = FlightRecorder(), FlightRecorder()
         _, _, m1 = run_scenario(packed, sc, mesh=mesh, recorder=rec1)
         _, _, m2 = run_scenario(packed, sc, mesh=mesh, recorder=rec2)
-        rec1.detach_jit_probe()
-        rec2.detach_jit_probe()
         assert m1 == m2, f"{name}: scenario replay is not deterministic"
         assert rec1.dump_json() == rec2.dump_json(), \
             f"{name}: flight-recorder dump is not replay-deterministic"

@@ -51,6 +51,7 @@ from repro.core.energy import (FRAME_CYCLES, AcceleratorSpec, EnergyReport,
 from repro.core.lif import LIFParams, lif_rollout
 from repro.core.memories import DispatchStats, PackedTables
 from repro.core.quant import check_bits, lanes_per_byte, pack_signmag
+from repro.engine.tracing import stage
 from repro.kernels import ops
 from repro.kernels.event_synapse import DEFAULT_BLOCK_D
 
@@ -285,34 +286,11 @@ def trace_count() -> int:
     return _trace_count
 
 
-_trace_listeners: list = []
-
-
-def add_trace_listener(fn) -> None:
-    """Subscribe ``fn(kind, donated)`` to jit (re)trace events — called once
-    per compile of an engine forward (kind ``"batched"`` or ``"sharded"``).
-    The flight recorder's jit probe lives here; listeners must never raise
-    (a probe failure must not poison a compile)."""
-    if fn not in _trace_listeners:
-        _trace_listeners.append(fn)
-
-
-def remove_trace_listener(fn) -> None:
-    if fn in _trace_listeners:
-        _trace_listeners.remove(fn)
-
-
-def _bump_trace(kind: str = "batched", donated: bool = False) -> None:
+def _bump_trace() -> None:
     """Called from inside traced function bodies: python side effects execute
-    exactly once per (re)trace, which is precisely what we want to count.
-    Fans the event out to any registered trace listeners."""
+    exactly once per (re)trace, which is precisely what we want to count."""
     global _trace_count
     _trace_count += 1
-    for fn in list(_trace_listeners):
-        try:
-            fn(kind, donated)
-        except Exception:
-            pass
 
 
 def _lif_scan(currents: jax.Array, lif: LIFParams) -> jax.Array:
@@ -357,21 +335,32 @@ def _forward_impl(packed: PackedModel, spikes: jax.Array,
     mesh path bit-exact by construction."""
     b, t, _ = spikes.shape
     outs = []
-    for layer in packed.layers:
-        events = ops.events_from_spikes(spikes.reshape(b * t, layer.n_src),
-                                        _mem_e_depth(layer, max_events))
-        if layer.w_packed is not None:
-            # packed-operand route: the kernel gathers sub-byte ladder words
-            # and dequantizes in-device — no f32 weight tile exists
-            currents = ops.event_synapse_packed(
-                events, layer.w_packed, layer.scale, bits=layer.bits,
-                block_d=packed.block_d)
-        else:
-            # rounds target disjoint dest columns -> one fused kernel call
-            w = _layer_weights(layer, packed.weight_dict)
-            currents = ops.event_synapse(events, w, block_d=packed.block_d)
-        out = _lif_scan(currents.reshape(b, t, layer.n_dest_pad), packed.lif)
-        spikes = out[..., :layer.n_dest]
+    for i, layer in enumerate(packed.layers):
+        # stable scope paths (layer<i>/mem_e|synapse|lif) in every op's
+        # metadata, so a profile names each step's device time
+        with jax.named_scope(f"layer{i}"):
+            with jax.named_scope("mem_e"):
+                events = ops.events_from_spikes(
+                    spikes.reshape(b * t, layer.n_src),
+                    _mem_e_depth(layer, max_events))
+            with jax.named_scope("synapse"):
+                if layer.w_packed is not None:
+                    # packed-operand route: the kernel gathers sub-byte
+                    # ladder words and dequantizes in-device — no f32
+                    # weight tile exists
+                    currents = ops.event_synapse_packed(
+                        events, layer.w_packed, layer.scale,
+                        bits=layer.bits, block_d=packed.block_d)
+                else:
+                    # rounds target disjoint dest columns -> one fused
+                    # kernel call
+                    w = _layer_weights(layer, packed.weight_dict)
+                    currents = ops.event_synapse(events, w,
+                                                 block_d=packed.block_d)
+            with jax.named_scope("lif"):
+                out = _lif_scan(currents.reshape(b, t, layer.n_dest_pad),
+                                packed.lif)
+                spikes = out[..., :layer.n_dest]
         outs.append(spikes)
     return outs
 
@@ -379,7 +368,7 @@ def _forward_impl(packed: PackedModel, spikes: jax.Array,
 @functools.partial(jax.jit, static_argnames=("max_events",))
 def _forward(packed: PackedModel, spikes: jax.Array,
              max_events: int | None) -> list[jax.Array]:
-    _bump_trace("batched")
+    _bump_trace()
     return _forward_impl(packed, spikes, max_events)
 
 
@@ -395,7 +384,7 @@ def _forward_donated(packed: PackedModel, spikes: jax.Array,
     compiled executable, not the call), chosen by ``run_batched(donate=)``;
     CPU XLA implements no donation, so the single-device default stays off
     there."""
-    _bump_trace("batched", donated=True)
+    _bump_trace()
     return _forward_impl(packed, spikes, max_events)
 
 
@@ -507,7 +496,8 @@ def _finalize(packed: PackedModel, in_spikes: np.ndarray,
     """Device outputs -> :class:`BatchedRunResult`, including the host-side
     dispatch accounting.  Shared by ``run_batched`` and ``run_sharded`` so
     the two entry points cannot drift apart on the stats surface."""
-    out = np.asarray(layer_outs[-1])
+    with stage("fetch"):
+        out = np.asarray(layer_outs[-1])
     bits = [l.bits for l in packed.layers]
     if not with_stats:
         return BatchedRunResult(out_spikes=out, per_layer_stats=[],
@@ -549,10 +539,12 @@ def run_batched(model: MappedModel | PackedModel, in_spikes: np.ndarray,
     reuse (default: on unless the backend is CPU, which lacks donation).
     """
     packed = model if isinstance(model, PackedModel) else model.pack()
-    spikes = jnp.asarray(np.asarray(in_spikes, dtype=np.float32))
+    with stage("upload"):
+        spikes = jnp.asarray(np.asarray(in_spikes, dtype=np.float32))
     assert spikes.ndim == 3 and spikes.shape[2] == packed.n_in, \
         f"expected [B, T, {packed.n_in}], got {spikes.shape}"
     fwd = _forward_donated if should_donate(donate) else _forward
-    layer_outs = fwd(packed, spikes, max_events)
+    with stage("launch"):
+        layer_outs = fwd(packed, spikes, max_events)
     return _finalize(packed, np.asarray(in_spikes, dtype=np.float32),
                      layer_outs, max_events, sn_capacity_rows, with_stats)

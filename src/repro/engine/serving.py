@@ -32,6 +32,7 @@ from repro.core.energy import FRAME_CYCLES, EnergyReport, energy_model
 from repro.core.memories import DispatchStats
 from repro.engine import batched_run as br
 from repro.engine.sharded_run import run_sharded
+from repro.engine.tracing import stage
 
 _log = logging.getLogger(__name__)
 
@@ -266,15 +267,16 @@ def execute_plan(packed: "br.PackedModel", streams, plan: BatchPlan, *,
     replay-deterministic.
     """
     clock = time.monotonic if now is None else now
-    if span_log is not None:
-        t_pad0 = clock()
-    padded = np.zeros((plan.b_pad, plan.t_pad, packed.n_in),
-                      dtype=np.float32)
-    for row, i in enumerate(plan.indices):
-        padded[row, :streams[i].shape[0]] = streams[i]
-    if span_log is not None:
-        span_log.append(("pad", t_pad0, clock(),
-                         {"b_pad": plan.b_pad, "t_pad": plan.t_pad}))
+    with stage("pad"):
+        if span_log is not None:
+            t_pad0 = clock()
+        padded = np.zeros((plan.b_pad, plan.t_pad, packed.n_in),
+                          dtype=np.float32)
+        for row, i in enumerate(plan.indices):
+            padded[row, :streams[i].shape[0]] = streams[i]
+        if span_log is not None:
+            span_log.append(("pad", t_pad0, clock(),
+                             {"b_pad": plan.b_pad, "t_pad": plan.t_pad}))
     t0 = time.perf_counter()
     if mesh is None:
         res = br.run_batched(packed, padded, max_events=max_events,
@@ -295,13 +297,15 @@ def execute_plan(packed: "br.PackedModel", streams, plan: BatchPlan, *,
             res.out_spikes[row, :streams[i].shape[0]].sum()
             for row, i in enumerate(plan.indices))),
         "seconds": dt}
-    if span_log is not None:
-        t_sl0 = clock()
-    results = [_slice_request(res, row, streams[i].shape[0], with_stats)
-               for row, i in enumerate(plan.indices)]
-    if span_log is not None:
-        span_log.append(("slice", t_sl0, clock(),
-                         {"n_requests": len(plan.indices)}))
+    with stage("slice"):
+        if span_log is not None:
+            t_sl0 = clock()
+        results = [_slice_request(res, row, streams[i].shape[0],
+                                  with_stats)
+                   for row, i in enumerate(plan.indices)]
+        if span_log is not None:
+            span_log.append(("slice", t_sl0, clock(),
+                             {"n_requests": len(plan.indices)}))
     return results, record
 
 

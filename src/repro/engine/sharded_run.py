@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.engine import batched_run as br
+from repro.engine.tracing import stage
 from repro.parallel.sharding import SNN_SERVE_RULES, ShardingRules
 
 
@@ -110,7 +111,7 @@ def _sharded_forward(mesh: Mesh, spec: PartitionSpec, donate: bool):
     bit-exactness hold by construction rather than by luck."""
 
     def fwd(packed, spikes, max_events):
-        br._bump_trace("sharded", donated=donate)
+        br._bump_trace()
         body = functools.partial(br._forward_impl, max_events=max_events)
         mapped = jax.shard_map(body, mesh=mesh,
                                in_specs=(PartitionSpec(), spec),
@@ -152,6 +153,9 @@ def run_sharded(model, in_spikes: np.ndarray, *,
     mesh = snn_serve_mesh() if mesh is None else mesh
     spec = batch_spec(mesh, spikes_np.shape)
     fwd = _sharded_forward(mesh, spec, br.should_donate(donate))
-    layer_outs = fwd(packed, jnp.asarray(spikes_np), max_events)
+    with stage("upload"):
+        spikes = jnp.asarray(spikes_np)
+    with stage("launch"):
+        layer_outs = fwd(packed, spikes, max_events)
     return br._finalize(packed, spikes_np, layer_outs, max_events,
                         sn_capacity_rows, with_stats)

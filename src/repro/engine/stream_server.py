@@ -82,7 +82,8 @@ from repro.engine.registry import (DEFAULT_MODEL, ModelEntry, ModelRegistry,
 from repro.engine.serving import (BatchPlan, BucketPolicy, RequestResult,
                                   execute_plan)
 from repro.engine.sharded_run import DeviceLossError, shrink_mesh
-from repro.engine.tracing import TIME_EDGES, FlightRecorder, Histogram
+from repro.engine.tracing import (TIME_EDGES, FlightRecorder, Histogram,
+                                  stage)
 
 _log = logging.getLogger(__name__)
 
@@ -455,8 +456,6 @@ class StreamServer:
         # VirtualClock replay produces byte-identical dumps; None = tracing
         # off, with zero observable effect on served bits (tested).
         self.tracer = tracer
-        if tracer is not None:
-            tracer.attach_jit_probe()
         self.metrics = ServerMetrics()
         # execute_plan records / rejection log, last METRICS_WINDOW entries
         self.telemetry: collections.deque = \
@@ -897,6 +896,13 @@ class StreamServer:
 
     def _dispatch(self, key: tuple[str, int, int], k: int,
                   forced: bool) -> None:
+        with stage("dispatch", seq=self.metrics.dispatches,
+                   b_pad=self._policy_for(key[0]).b_bucket(k), n_requests=k,
+                   why="deadline" if forced else "full_bucket"):
+            self._dispatch_group(key, k, forced)
+
+    def _dispatch_group(self, key: tuple[str, int, int], k: int,
+                        forced: bool) -> None:
         name, gen, t_pad = key
         entry = self._entry_for((name, gen))
         q = self._pending[key]
@@ -949,42 +955,45 @@ class StreamServer:
         m.fill.append(k / b_pad)
         m.queue_depth = self._n_pending
         if tr is not None:
-            # dispatch-level attrs shared by every member trace: the
-            # deterministic slice of the telemetry record (``seconds`` is
-            # wall-measured and would break byte-identical replays), the
-            # scheduler's *why* (deadline-forced vs full bucket), and the
-            # per-layer hardware roll-up sampled from the engine results.
-            det = {kk: record[kk] for kk in
-                   ("seq", "b_pad", "t_pad", "n_requests", "events",
-                    "out_spikes")}
-            det.update(model=name, generation=gen)
-            why = "deadline" if forced else "full_bucket"
-            grp_deadline = min(r.deadline for r in reqs)
-            hw_layers: list[dict] = []
-            if results and results[0].stats:
-                for li in range(len(results[0].stats)):
-                    hw_layers.append({
-                        "layer": li,
-                        "events": sum(int(r.stats[li].events.sum())
-                                      for r in results),
-                        "engine_ops": sum(int(r.stats[li].engine_ops.sum())
+            with stage("record"):
+                # dispatch-level attrs shared by every member trace: the
+                # deterministic slice of the telemetry record (``seconds``
+                # is wall-measured and would break byte-identical
+                # replays), the scheduler's *why* (deadline-forced vs full
+                # bucket), and the per-layer hardware roll-up sampled from
+                # the engine results.
+                det = {kk: record[kk] for kk in
+                       ("seq", "b_pad", "t_pad", "n_requests", "events",
+                        "out_spikes")}
+                det.update(model=name, generation=gen)
+                why = "deadline" if forced else "full_bucket"
+                grp_deadline = min(r.deadline for r in reqs)
+                hw_layers: list[dict] = []
+                if results and results[0].stats:
+                    for li in range(len(results[0].stats)):
+                        hw_layers.append({
+                            "layer": li,
+                            "events": sum(int(r.stats[li].events.sum())
                                           for r in results),
-                        "cycles": sum(int(r.stats[li].cycles.sum())
-                                      for r in results),
-                        "rows_touched": sum(
-                            int(r.stats[li].rows_touched.sum())
-                            for r in results),
-                        "util_mean": float(np.mean(
-                            [float(np.mean(r.util[li])) for r in results])),
-                    })
-                if results[0].spec is not None:
-                    ereps = [r.energy() for r in results]
-                    det["energy_j"] = float(sum(
-                        er.dynamic_j + er.static_j for er in ereps))
-                    det["tops_per_w"] = float(np.mean(
-                        [er.tops_per_w for er in ereps]))
-            tr.observe("service_s", end_t - dispatch_t)
-            tr.observe("fill", k / b_pad)
+                            "engine_ops": sum(int(r.stats[li].engine_ops.sum())
+                                              for r in results),
+                            "cycles": sum(int(r.stats[li].cycles.sum())
+                                          for r in results),
+                            "rows_touched": sum(
+                                int(r.stats[li].rows_touched.sum())
+                                for r in results),
+                            "util_mean": float(np.mean(
+                                [float(np.mean(r.util[li]))
+                                 for r in results])),
+                        })
+                    if results[0].spec is not None:
+                        ereps = [r.energy() for r in results]
+                        det["energy_j"] = float(sum(
+                            er.dynamic_j + er.static_j for er in ereps))
+                        det["tops_per_w"] = float(np.mean(
+                            [er.tops_per_w for er in ereps]))
+                tr.observe("service_s", end_t - dispatch_t)
+                tr.observe("fill", k / b_pad)
         for req, res in zip(reqs, results):
             self._completed.append((req.rid, res))
             if self.on_completion is not None:
@@ -999,31 +1008,34 @@ class StreamServer:
             mm.deadline_misses += int(missed)
             self._slo_misses.append(missed)
             if tr is not None:
-                tr.span(req.rid, "queue", req.arrival_t, dispatch_t)
-                sched = {"why": why, "n_requests": k}
-                if grp_deadline != math.inf:
-                    sched["group_deadline"] = float(grp_deadline)
-                tr.span(req.rid, "schedule", dispatch_t, dispatch_t, **sched)
-                # lifecycle order: pad -> dispatch -> slice (the pad/slice
-                # micro-spans come off execute_plan's span_log)
-                for kind, s0, s1, attrs in span_log:
-                    if kind == "pad":
-                        tr.span(req.rid, kind, s0, s1, **attrs)
-                tr.span(req.rid, "dispatch", dispatch_t, end_t, **det)
-                for kind, s0, s1, attrs in span_log:
-                    if kind != "pad":
-                        tr.span(req.rid, kind, s0, s1, **attrs)
-                for hw in hw_layers:
-                    tr.span(req.rid, "hw", dispatch_t, end_t, **hw)
-                tr.span(req.rid, "complete", end_t, end_t,
-                        latency_s=end_t - req.arrival_t, missed=missed)
-                if missed:
-                    tr.anomaly("deadline_miss", t=end_t, rid=req.rid,
-                               deadline=float(req.deadline),
-                               late_s=end_t - req.deadline, model=name)
-                tr.observe("ttfd_s", dispatch_t - req.arrival_t)
-                tr.observe("latency_s", end_t - req.arrival_t)
-                tr.complete(req.rid, end_t)
+                with stage("record"):
+                    tr.span(req.rid, "queue", req.arrival_t, dispatch_t)
+                    sched = {"why": why, "n_requests": k}
+                    if grp_deadline != math.inf:
+                        sched["group_deadline"] = float(grp_deadline)
+                    tr.span(req.rid, "schedule", dispatch_t, dispatch_t,
+                            **sched)
+                    # lifecycle order: pad -> dispatch -> slice (the
+                    # pad/slice micro-spans come off execute_plan's
+                    # span_log)
+                    for kind, s0, s1, attrs in span_log:
+                        if kind == "pad":
+                            tr.span(req.rid, kind, s0, s1, **attrs)
+                    tr.span(req.rid, "dispatch", dispatch_t, end_t, **det)
+                    for kind, s0, s1, attrs in span_log:
+                        if kind != "pad":
+                            tr.span(req.rid, kind, s0, s1, **attrs)
+                    for hw in hw_layers:
+                        tr.span(req.rid, "hw", dispatch_t, end_t, **hw)
+                    tr.span(req.rid, "complete", end_t, end_t,
+                            latency_s=end_t - req.arrival_t, missed=missed)
+                    if missed:
+                        tr.anomaly("deadline_miss", t=end_t, rid=req.rid,
+                                   deadline=float(req.deadline),
+                                   late_s=end_t - req.deadline, model=name)
+                    tr.observe("ttfd_s", dispatch_t - req.arrival_t)
+                    tr.observe("latency_s", end_t - req.arrival_t)
+                    tr.complete(req.rid, end_t)
         if (entry.noise is not None and self.noise_probe_every
                 and mm.dispatches % self.noise_probe_every == 0):
             self._noise_probe(entry, reqs, results, streams, plan)

@@ -29,12 +29,14 @@ measured per-stage breakdowns, not end-to-end averages):
     these, so long soaks never silently forget the tail the way the
     bounded ``METRICS_WINDOW`` deque does; the windowed values survive
     under explicit ``recent_*`` keys.
-  * jit probe — :meth:`FlightRecorder.attach_jit_probe` subscribes to the
-    engine's retrace counter (:func:`repro.engine.batched_run
-    .add_trace_listener`), so compile and donation events land in
-    ``jit_events``.  They are deliberately **excluded** from ``dump()``:
-    the first replay of a trace compiles and the second hits the cache, so
-    including them would break the byte-identical-replay contract.
+  * :func:`stage` — the server's stages (socket read, admission,
+    scheduler poll, padding, upload, launch, fetch, slicing, recorder
+    bookkeeping, result encoding) as ``serve.<name>`` annotations in the
+    JAX profiler's trace, on the same clock as the device's operations.
+    They record only while a profiler session runs, keep no store here,
+    and add no synchronization, so each idle gap on the device can be
+    given to the host stage that covered it (``docs/OBSERVABILITY.md``,
+    "Profiler stages").
 
 Determinism contract (tested, ``tests/test_tracing.py``): two
 ``run_scenario`` replays of the same scenario yield byte-identical
@@ -51,7 +53,7 @@ import dataclasses
 import json
 import math
 
-from repro.engine import batched_run as br
+from jax.profiler import TraceAnnotation
 
 # The span taxonomy, in request-lifecycle order.  Locked by
 # tests/test_tracing.py and the docs/OBSERVABILITY.md span table
@@ -77,6 +79,14 @@ TIME_EDGES = tuple(10.0 ** (-6.0 + i / 8.0) for i in range(65))
 
 # Linear edges for ratios in (0, 1] (bucket fill).
 RATIO_EDGES = tuple((i + 1) / 32.0 for i in range(32))
+
+
+def stage(name: str, **attrs) -> TraceAnnotation:
+    """Context manager marking one stage of the served path as
+    ``serve.<name>`` in the JAX profiler's trace, with ``attrs`` (plain
+    scalars) as its arguments.  Records only while a profiler session is
+    running; it never blocks on the device."""
+    return TraceAnnotation(f"serve.{name}", **attrs)
 
 
 class Histogram:
@@ -222,12 +232,6 @@ class FlightRecorder:
         assert tuple(self.hist) == HIST_KEYS
         self.n_started = 0
         self.n_completed = 0
-        # jit compile/donation events from the engine's trace probe —
-        # kept OUT of dump() (first replay compiles, second is cached;
-        # including them would break byte-identical replays)
-        self.jit_events: collections.deque[dict] = \
-            collections.deque(maxlen=256)
-        self._probe_attached = False
 
     # ---------------------------------------------------------- lifecycle
 
@@ -290,25 +294,6 @@ class FlightRecorder:
     def observe(self, key: str, value: float) -> None:
         self.hist[key].add(value)
 
-    # ------------------------------------------------------------- probes
-
-    def jit_event(self, kind: str, donated: bool) -> None:
-        self.jit_events.append({"kind": kind, "donated": bool(donated)})
-
-    def attach_jit_probe(self) -> "FlightRecorder":
-        """Subscribe to the engine's (process-global) retrace probe; jit
-        compile + donation events then land in :attr:`jit_events`.
-        Idempotent; :meth:`detach_jit_probe` unsubscribes."""
-        if not self._probe_attached:
-            br.add_trace_listener(self.jit_event)
-            self._probe_attached = True
-        return self
-
-    def detach_jit_probe(self) -> None:
-        if self._probe_attached:
-            br.remove_trace_listener(self.jit_event)
-            self._probe_attached = False
-
     # ------------------------------------------------------------ queries
 
     def trace(self, rid: int) -> RequestTrace | None:
@@ -331,8 +316,8 @@ class FlightRecorder:
         server-level events, lifetime anomaly counts, and the cumulative
         histograms.  Everything inside comes off the server's clock —
         under a VirtualClock two replays of the same trace produce
-        identical dumps (``jit_events`` and wall seconds are excluded for
-        exactly this reason)."""
+        identical dumps (wall seconds are excluded for exactly this
+        reason)."""
         return {
             "n_started": int(self.n_started),
             "n_completed": int(self.n_completed),

@@ -57,7 +57,7 @@ from repro.engine.registry import (ModelRegistry,  # noqa: E402
                                    UnknownModelError)
 from repro.engine.serving import BucketPolicy  # noqa: E402
 from repro.engine.stream_server import SLOPolicy, StreamServer  # noqa: E402
-from repro.engine.tracing import FlightRecorder  # noqa: E402
+from repro.engine.tracing import FlightRecorder, stage  # noqa: E402
 
 _log = logging.getLogger(__name__)
 
@@ -181,7 +181,9 @@ class SpikeSocketServer:
             conn, req_id = owner
             conn.inflight -= 1
             self.served += 1
-            self._send(conn, ingest.encode_result(req_id, res.out_spikes))
+            with stage("encode"):
+                self._send(conn,
+                           ingest.encode_result(req_id, res.out_spikes))
 
     def _on_request(self, conn: _Conn, frame: ingest.Frame) -> None:
         # resolve the tenant and validate the claimed shape BEFORE
@@ -289,9 +291,14 @@ class SpikeSocketServer:
             return
         conn = self._conns[sock]
         try:
-            chunk = sock.recv(1 << 16)
+            with stage("read"):
+                chunk = sock.recv(1 << 16)
+                frames = conn.decoder.feed(chunk)
         except OSError:
             self._drop(conn)
+            return
+        except ingest.ProtocolError as e:
+            self._protocol_error(conn, e)
             return
         if not chunk:
             # EOF: finish its in-flight, then close.  Unregister the read
@@ -305,11 +312,12 @@ class SpikeSocketServer:
                 self._sel.unregister(sock)
             return
         try:
-            for frame in conn.decoder.feed(chunk):
+            for frame in frames:
                 if frame.kind == ingest.KIND_ADMIN:
                     self._on_admin(conn, frame)
                 elif frame.kind == ingest.KIND_REQUEST:
-                    self._on_request(conn, frame)
+                    with stage("admit"):
+                        self._on_request(conn, frame)
                 else:
                     raise ingest.ProtocolError(
                         f"client sent frame kind {frame.kind}, "
@@ -318,14 +326,17 @@ class SpikeSocketServer:
                 self._deliver(self.server.collect())
                 self._drain_new_rejections()
         except ingest.ProtocolError as e:
-            # the stream is corrupt beyond resync: discard this
-            # connection's buffered bytes (FrameDecoder.reset) so nothing
-            # re-parses them, then drop only this client — other
-            # connections keep their own decoders and never notice
-            dropped = conn.decoder.reset()
-            _log.warning("socket_serve: protocol error, dropping client "
-                         "(%d buffered bytes discarded): %s", dropped, e)
-            self._drop(conn)
+            self._protocol_error(conn, e)
+
+    def _protocol_error(self, conn: _Conn, err: ingest.ProtocolError) -> None:
+        # the stream is corrupt beyond resync: discard this connection's
+        # buffered bytes (FrameDecoder.reset) so nothing re-parses them,
+        # then drop only this client — other connections keep their own
+        # decoders and never notice
+        dropped = conn.decoder.reset()
+        _log.warning("socket_serve: protocol error, dropping client "
+                     "(%d buffered bytes discarded): %s", dropped, err)
+        self._drop(conn)
 
     # ---------------------------------------------------------------- loop
 
@@ -349,17 +360,20 @@ class SpikeSocketServer:
             nd = self.server.next_deadline()
             timeout = (_TICK_S if nd is None
                        else min(max(nd - self.server.now(), 0.0), _TICK_S))
-            events = self._sel.select(timeout)
+            with stage("wait", queued=self.server.queue_depth):
+                events = self._sel.select(timeout)
             if events:
                 last_activity = time.monotonic()
             for key, _ in events:
                 self._on_readable(key.fileobj)
-            self._tick()
-            if (self.server.queue_depth > 0 and not events
-                    and self.server.next_deadline() is None
-                    and time.monotonic() - last_activity > idle_flush_s):
-                self._deliver(self.server.flush())
-                self._drain_new_rejections()
+            with stage("poll"):
+                self._tick()
+                if (self.server.queue_depth > 0 and not events
+                        and self.server.next_deadline() is None
+                        and time.monotonic() - last_activity
+                        > idle_flush_s):
+                    self._deliver(self.server.flush())
+                    self._drain_new_rejections()
             if max_requests is not None and self.served >= max_requests:
                 break
         self._deliver(self.server.flush())
