@@ -232,9 +232,17 @@ def _slice_request(res: "br.BatchedRunResult", row: int, t: int,
 # at dispatch (the StreamServer passes its pluggable clock's now, so
 # VirtualClock replays stamp deterministic timestamps; ``seconds`` stays
 # wall-measured engine time) — records shared through one ``telemetry=``
-# list across rounds are now self-ordering.
+# list across rounds are now self-ordering.  ``loop_fill`` is layer 0's
+# :func:`loop_fill`.
 TELEMETRY_KEYS = ("seq", "ts", "b_pad", "t_pad", "n_requests", "events",
-                  "out_spikes", "seconds")
+                  "loop_fill", "out_spikes", "seconds")
+
+
+def loop_fill(step_events, depth: int, rows: int) -> float:
+    """Walked share of ``rows`` event lists ``depth`` slots deep, given each
+    real row's events (``step_events``; padding rows hold none): a row's
+    loop runs to its last event, at most ``depth`` slots."""
+    return float(np.minimum(step_events, depth).sum()) / (rows * depth)
 
 
 def execute_plan(packed: "br.PackedModel", streams, plan: BatchPlan, *,
@@ -287,12 +295,17 @@ def execute_plan(packed: "br.PackedModel", streams, plan: BatchPlan, *,
                           sn_capacity_rows=sn_capacity_rows,
                           with_stats=with_stats, donate=donate)
     dt = time.perf_counter() - t0
+    step_events = np.concatenate([(streams[i] > 0).sum(axis=1)
+                                  for i in plan.indices])
     record = {
         "seq": int(seq),
         "ts": float(time.monotonic() if ts is None else ts),
         "b_pad": plan.b_pad, "t_pad": plan.t_pad,
         "n_requests": len(plan.indices),
-        "events": int(sum((streams[i] > 0).sum() for i in plan.indices)),
+        "events": int(step_events.sum()),
+        "loop_fill": loop_fill(
+            step_events, br._mem_e_depth(packed.layers[0], max_events),
+            plan.b_pad * plan.t_pad),
         "out_spikes": int(sum(
             res.out_spikes[row, :streams[i].shape[0]].sum()
             for row, i in enumerate(plan.indices))),

@@ -80,7 +80,7 @@ from repro.engine import batched_run as br
 from repro.engine.registry import (DEFAULT_MODEL, ModelEntry, ModelRegistry,
                                    UnknownModelError)
 from repro.engine.serving import (BatchPlan, BucketPolicy, RequestResult,
-                                  execute_plan)
+                                  execute_plan, loop_fill)
 from repro.engine.sharded_run import DeviceLossError, shrink_mesh
 from repro.engine.tracing import (TIME_EDGES, FlightRecorder, Histogram,
                                   stage)
@@ -964,17 +964,22 @@ class StreamServer:
                 # the engine results.
                 det = {kk: record[kk] for kk in
                        ("seq", "b_pad", "t_pad", "n_requests", "events",
-                        "out_spikes")}
+                        "loop_fill", "out_spikes")}
                 det.update(model=name, generation=gen)
                 why = "deadline" if forced else "full_bucket"
                 grp_deadline = min(r.deadline for r in reqs)
                 hw_layers: list[dict] = []
                 if results and results[0].stats:
                     for li in range(len(results[0].stats)):
+                        steps = np.concatenate([r.stats[li].events
+                                                for r in results])
+                        depth = br._mem_e_depth(entry.packed.layers[li],
+                                                self.max_events)
                         hw_layers.append({
                             "layer": li,
-                            "events": sum(int(r.stats[li].events.sum())
-                                          for r in results),
+                            "events": int(steps.sum()),
+                            "loop_fill": loop_fill(steps, depth,
+                                                   b_pad * t_pad),
                             "engine_ops": sum(int(r.stats[li].engine_ops.sum())
                                               for r in results),
                             "cycles": sum(int(r.stats[li].cycles.sum())

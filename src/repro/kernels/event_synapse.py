@@ -14,7 +14,10 @@ dimension — the "engine" axis onto which virtual neurons are packed.
 
 The event list is padded to a static length E (MEM_E depth).  Padding entries
 are -1 and are masked — the pad factor is the same overflow budget the paper
-provisions for the utilization spikes of Figs 6-7.
+provisions for the utilization spikes of Figs 6-7.  The loop walks a row's
+slots only up to its last valid one (a per-row bound computed from the list
+itself and handed to the kernel in SMEM), so a sparse row costs its events,
+not E; the trailing padding it skips would only have added ``0.0``.
 """
 
 from __future__ import annotations
@@ -43,13 +46,13 @@ def _lane_block(n: int, want: int) -> int:
     return min(b, n)
 
 
-def _accumulate(events_ref, read_row, n_planes: int, width: int):
+def _accumulate(events_ref, bound_ref, read_row, n_planes: int, width: int):
     """The per-event dispatch loop shared by both kernels: for each MEM_E
-    slot in order, read the event's source index from SMEM (a scalar read)
-    and add the weight row ``read_row(plane, idx)`` ([1, width] f32) of every
+    slot in order, up to the row's bound (its last valid slot + 1, a scalar
+    in SMEM), read the event's source index from SMEM (a scalar read) and
+    add the weight row ``read_row(plane, idx)`` ([1, width] f32) of every
     plane — one f32 add per event per column, in ascending source order, and
-    an exact ``0.0`` for the ``-1`` padding slots."""
-    n_events = events_ref.shape[1]
+    an exact ``0.0`` for a ``-1`` padding slot before the bound."""
 
     def body(e, accs):
         idx = events_ref[0, e]
@@ -59,22 +62,36 @@ def _accumulate(events_ref, read_row, n_planes: int, width: int):
                      for p, acc in enumerate(accs))
 
     zero = jnp.zeros((1, width), jnp.float32)
-    return jax.lax.fori_loop(0, n_events, body, (zero,) * n_planes)
+    return jax.lax.fori_loop(0, bound_ref[0, 0], body, (zero,) * n_planes)
 
 
-def _event_synapse_kernel(events_ref, weights_ref, out_ref):
-    """events [1, E] i32 (SMEM); weights [n_src, BD] f32; out [1, BD] f32."""
+def _event_synapse_kernel(events_ref, bound_ref, weights_ref, out_ref):
+    """events [1, E] i32 (SMEM); bound [1, 1] i32 (SMEM); weights
+    [n_src, BD] f32; out [1, BD] f32."""
     (acc,) = _accumulate(
-        events_ref, lambda _, idx: weights_ref[pl.ds(idx, 1), :], 1,
-        out_ref.shape[1])
+        events_ref, bound_ref, lambda _, idx: weights_ref[pl.ds(idx, 1), :],
+        1, out_ref.shape[1])
     out_ref[...] = acc
 
 
-def _event_spec(n_events: int) -> pl.BlockSpec:
-    """One row's event list per grid step, in SMEM so the loop reads each
-    index as a scalar: ``[rows, 1, E]`` blocked ``(None, 1, E)``."""
-    return pl.BlockSpec((None, 1, n_events), lambda i, j: (i, 0, 0),
-                        memory_space=pltpu.SMEM)
+def _event_operands(events: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """A row's event list and its loop bound, shaped for :func:`_event_specs`:
+    events ``[B, 1, E]`` and, per row, the last valid slot + 1 ``[B, 1, 1]``
+    (0 for a row of padding) — the count of events when the list is
+    compacted to the front, as ``events_from_spikes`` writes it."""
+    b, n_events = events.shape
+    slot = jnp.arange(1, n_events + 1, dtype=jnp.int32)
+    bound = jnp.max(jnp.where(events >= 0, slot, 0), axis=1)
+    return events.reshape(b, 1, n_events), bound.reshape(b, 1, 1)
+
+
+def _event_specs(n_events: int) -> list[pl.BlockSpec]:
+    """One row's event list and loop bound per grid step, in SMEM so the
+    loop reads each as a scalar: ``(None, 1, E)`` and ``(None, 1, 1)``
+    blocks of the operands :func:`_event_operands` makes."""
+    return [pl.BlockSpec((None, 1, width), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.SMEM)
+            for width in (n_events, 1)]
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -95,21 +112,21 @@ def event_synapse(events: jax.Array, weights: jax.Array,
         _event_synapse_kernel,
         grid=(b, n_dest // bd),
         in_specs=[
-            _event_spec(n_events),
+            *_event_specs(n_events),
             pl.BlockSpec((n_src, bd), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((None, 1, bd), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, 1, n_dest), weights.dtype),
         interpret=interpret,
-    )(events.reshape(b, 1, n_events), weights)
+    )(*_event_operands(events), weights)
     return out.reshape(b, n_dest)
 
 
-def _event_synapse_packed_kernel(events_ref, packed_ref, scale_ref, out_ref,
-                                 tile_ref, *, bits: int):
-    """events [1, E] i32 (SMEM); packed [n_src, BDB] int8 (sign-magnitude
-    lanes); scale [1, 1] f32 (SMEM); out [L, BDB] f32 with ``L = 8/bits``;
-    tile [L, n_src, BDB] f32 VMEM scratch.
+def _event_synapse_packed_kernel(events_ref, bound_ref, packed_ref, scale_ref,
+                                 out_ref, tile_ref, *, bits: int):
+    """events [1, E] i32 (SMEM); bound [1, 1] i32 (SMEM); packed
+    [n_src, BDB] int8 (sign-magnitude lanes); scale [1, 1] f32 (SMEM); out
+    [L, BDB] f32 with ``L = 8/bits``; tile [L, n_src, BDB] f32 VMEM scratch.
 
     The weight tile arrives packed — ``bits/32`` of the f32 VMEM footprint,
     the twin of A-SYN storing sub-byte ladder words.  It is unpacked *once
@@ -136,8 +153,8 @@ def _event_synapse_packed_kernel(events_ref, packed_ref, scale_ref, out_ref,
         q = (mag - 2 * sign * mag).astype(jnp.float32)
         tile_ref[s] = q * scale                   # fl32(q * scale), per elem
     accs = _accumulate(
-        events_ref, lambda s, idx: tile_ref[s, pl.ds(idx, 1), :], ell,
-        out_ref.shape[1])
+        events_ref, bound_ref, lambda s, idx: tile_ref[s, pl.ds(idx, 1), :],
+        ell, out_ref.shape[1])
     for s, acc in enumerate(accs):
         out_ref[pl.ds(s, 1), :] = acc
 
@@ -172,7 +189,7 @@ def event_synapse_packed(events: jax.Array, packed_w: jax.Array,
         functools.partial(_event_synapse_packed_kernel, bits=bits),
         grid=(b, n_bytes // bdb),
         in_specs=[
-            _event_spec(n_events),
+            *_event_specs(n_events),
             pl.BlockSpec((n_src, bdb), lambda i, j: (0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
@@ -180,7 +197,7 @@ def event_synapse_packed(events: jax.Array, packed_w: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, ell, n_bytes), jnp.float32),
         scratch_shapes=[pltpu.VMEM((ell, n_src, bdb), jnp.float32)],
         interpret=interpret,
-    )(events.reshape(b, 1, n_events), packed_w, scale)
+    )(*_event_operands(events), packed_w, scale)
     # plane s of byte j is destination j * ell + s
     return out.transpose(0, 2, 1).reshape(b, n_dest)
 

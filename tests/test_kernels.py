@@ -9,7 +9,8 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core.quant import pack_signmag
 from repro.kernels import ops
-from repro.kernels.event_synapse import _events_from_spikes_argsort
+from repro.kernels.event_synapse import (_event_operands,
+                                         _events_from_spikes_argsort)
 from repro.kernels.ref import (c2c_matmul_ladder_ref, c2c_matmul_ref,
                                event_synapse_packed_ref, event_synapse_ref,
                                lif_update_ref)
@@ -140,6 +141,72 @@ def test_event_synapse_packed_property(seed, bits, density):
                                atol=1e-4)
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(ops.event_synapse(ev, jnp.asarray(w))))
+
+
+# ------------------------------------ event loop bounded by a row's events
+
+N_SRC = 24
+
+
+def _event_lists(case: str) -> np.ndarray:
+    """Padded event lists ``[rows, N_SRC]`` whose rows end at different
+    slots: ``fills`` mixes an empty row, one event, every slot and a sparse
+    compacted row; ``gaps`` holds a ``-1`` before a valid slot, so a row's
+    last valid slot + 1 exceeds its count of events; ``padding`` is all
+    ``-1``."""
+    pad = np.full((4, N_SRC), -1, np.int32)
+    if case == "fills":
+        pad[1, 0] = 17
+        pad[2] = np.arange(N_SRC)
+        pad[3, :5] = [0, 3, 4, 11, 23]
+    elif case == "gaps":
+        pad[0, [0, 2]] = [3, 5]
+        pad[1, 4] = 9
+        pad[2, [1, N_SRC - 1]] = [2, 20]
+        pad[3, :3] = [1, 6, 7]
+    return pad
+
+
+def _full_walk(events: np.ndarray, w: np.ndarray) -> np.ndarray:
+    """The loop over every MEM_E slot: one f32 add per slot in order, an
+    exact ``0.0`` for a ``-1`` — what the kernels computed before their loop
+    stopped at each row's last event."""
+    out = np.zeros((events.shape[0], w.shape[1]), np.float32)
+    for r, row in enumerate(events):
+        for idx in row:
+            out[r] = out[r] + (w[idx] if idx >= 0 else np.float32(0.0))
+    return out
+
+
+@pytest.mark.parametrize("case", ["fills", "gaps", "padding"])
+@pytest.mark.parametrize("kernel", ["f32", "packed8", "packed4"])
+def test_event_loop_bound_bit_exact(rng, kernel, case):
+    """Stopping each row's loop at its last valid slot drops only trailing
+    ``+0.0`` adds: both kernels return the bits of the full walk, for rows
+    of any fill, a ``-1`` before a valid slot, and rows of padding alone;
+    the bound is the last valid slot + 1, not the count of events."""
+    events = _event_lists(case)
+    valid = events >= 0
+    last = np.where(valid.any(axis=1),
+                    N_SRC - np.argmax(valid[:, ::-1], axis=1), 0)
+    _, bound = _event_operands(jnp.asarray(events))
+    np.testing.assert_array_equal(np.asarray(bound).ravel(), last)
+    if case == "gaps":
+        assert np.any(last > valid.sum(axis=1))
+    if kernel == "f32":
+        w = rng.normal(size=(N_SRC, 256)).astype(np.float32)
+        out = ops.event_synapse(jnp.asarray(events), jnp.asarray(w),
+                                block_d=128)
+    else:
+        bits = int(kernel[len("packed"):])
+        q = _random_codes(rng, N_SRC, 256, bits)
+        scale = np.float32(0.011)
+        w = q.astype(np.float32) * scale
+        out = ops.event_synapse_packed(jnp.asarray(events),
+                                       jnp.asarray(pack_signmag(q, bits)),
+                                       scale, bits=bits, block_d=128)
+    np.testing.assert_array_equal(np.asarray(out).view(np.uint32),
+                                  _full_walk(events, w).view(np.uint32))
 
 
 # ------------------------------------------------- event-stream compaction

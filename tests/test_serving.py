@@ -18,11 +18,11 @@ from repro.core.accelerator import map_model, run
 from repro.core.energy import AcceleratorSpec
 from repro.core.layers import Conv2d, Dense, SumPool2d
 from repro.core.lif import LIFParams
-from repro.engine import (METRIC_KEYS, BucketPolicy, OverlongRequestError,
-                          ServerMetrics, StreamServer, TELEMETRY_KEYS,
-                          VirtualClock, plan_batches, run_bucketed,
-                          trace_count)
-from repro.engine.serving import BatchPlan
+from repro.engine import (METRIC_KEYS, BucketPolicy, FlightRecorder,
+                          OverlongRequestError, ServerMetrics, StreamServer,
+                          TELEMETRY_KEYS, VirtualClock, plan_batches,
+                          run_bucketed, trace_count)
+from repro.engine.serving import BatchPlan, loop_fill
 
 SPEC = AcceleratorSpec("serve-test", n_cores=3, n_engines=4, n_caps=8,
                        weight_mem_bytes=1 << 18)
@@ -204,6 +204,45 @@ def test_bucketed_telemetry(rng):
         == int(sum((s > 0).sum() for s in streams))
 
 
+@pytest.mark.parametrize("max_events", [None, 3], ids=["full_depth", "cap3"])
+def test_telemetry_loop_fill(rng, max_events):
+    """``loop_fill`` is layer 0's walked MEM_E slots over the full walk:
+    each row stops at its last event, so it is the events the depth admits
+    over ``b_pad * t_pad * depth`` — at full depth exactly ``events`` over
+    that — and lies in [0, 1]; the served server carries it on the record
+    and on its dispatch and per-layer ``hw`` spans."""
+    model = _dense_model(rng)
+    streams = _streams(rng, 14, [4, 9, 3])
+    policy = BucketPolicy(batch_sizes=(2,), time_steps=(4, 16))
+    telemetry = []
+    run_bucketed(model, streams, telemetry=telemetry, policy=policy,
+                 max_events=max_events)
+    depth = 14 if max_events is None else max_events
+    for t in telemetry:
+        slots = t["b_pad"] * t["t_pad"] * depth
+        assert 0.0 < t["loop_fill"] <= 1.0
+        if max_events is None:
+            assert t["loop_fill"] == t["events"] / slots
+        else:
+            assert t["loop_fill"] < t["events"] / slots   # rows overflow
+    rec = FlightRecorder()
+    server = StreamServer(model, clock=VirtualClock(), policy=policy,
+                          max_events=max_events, with_stats=True,
+                          tracer=rec)
+    rid = server.submit(streams[0])
+    server.flush()
+    (record,) = server.telemetry
+    assert record["loop_fill"] == loop_fill(
+        (streams[0] > 0).sum(axis=1), depth,
+        record["b_pad"] * record["t_pad"])
+    spans = rec.trace(rid).spans
+    dispatch = next(sp for sp in spans if sp.kind == "dispatch")
+    assert dispatch.attrs["loop_fill"] == record["loop_fill"]
+    hw = [sp.attrs for sp in spans if sp.kind == "hw"]
+    assert hw[0]["loop_fill"] == record["loop_fill"]
+    assert all(0.0 <= h["loop_fill"] <= 1.0 for h in hw)
+
+
 # -------------------------------------------------- metrics schema locks
 
 def test_telemetry_schema_locked(rng):
@@ -212,7 +251,7 @@ def test_telemetry_schema_locked(rng):
     and this test together.  ``seq``/``ts`` make records shared through one
     ``telemetry=`` list self-ordering across dispatch rounds."""
     assert TELEMETRY_KEYS == ("seq", "ts", "b_pad", "t_pad", "n_requests",
-                              "events", "out_spikes", "seconds")
+                              "events", "loop_fill", "out_spikes", "seconds")
     model = _dense_model(rng)
     telemetry = []
     run_bucketed(model, _streams(rng, 14, [4, 9]), telemetry=telemetry,

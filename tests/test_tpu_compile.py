@@ -73,6 +73,19 @@ def test_event_synapse_compiles_at_cifar_layer(one_chip, n_src, n_dest):
     assert KERNEL in text
 
 
+def test_event_synapse_compiles_at_bench_layer0(one_chip):
+    """Layer 0 of the benchmark's CIFAR10-DVS MLP (2x64x64 input: 8192
+    sources -> 1000 destinations, padded to 1024) over the 512 rows of a
+    (16, 32) bucket: the event loop's per-row bound, read from SMEM, must
+    lower with the kernel."""
+    n_src, rows = 8192, 16 * 32
+    n_dest_pad = br._pad_dest(LAYERS[0][1], DEFAULT_BLOCK_D)
+    text = _compiled_text(
+        event_synapse, _sds(one_chip, (rows, n_src), jnp.int32),
+        _sds(one_chip, (n_src, n_dest_pad), jnp.float32))
+    assert KERNEL in text
+
+
 @pytest.mark.parametrize("bits", [8, 4, 2])
 def test_event_synapse_packed_compiles_at_layer0(one_chip, bits):
     n_src, n_dest = LAYERS[0]

@@ -126,7 +126,7 @@ def test_trace_covers_request_lifecycle(packed):
     dispatch = next(sp for sp in tr.spans if sp.kind == "dispatch")
     # the deterministic union of the telemetry record (seconds excluded)
     for k in ("seq", "b_pad", "t_pad", "n_requests", "events",
-              "out_spikes", "model", "generation"):
+              "loop_fill", "out_spikes", "model", "generation"):
         assert k in dispatch.attrs, k
     assert "seconds" not in dispatch.attrs
     # with_stats=True: per-layer hardware roll-up + energy attribution
