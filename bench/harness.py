@@ -10,11 +10,26 @@ Everything that belongs to one configuration, traffic mix or metric sits in
 files of its own, found by the names in ``BENCHMARK.json``:
 
 * configuration ``<c>``: the ``file`` its entry names; its ``model`` field
-  names ``bench/models/<model>.py`` (weights from the seed, the served
-  program) and ``bench/models/<model>_ref.py`` (the plain reference);
+  names ``bench/models/<model>.py`` (the served program) and
+  ``bench/models/<model>_ref.py`` (the plain reference);
 * traffic mix ``<t>``: ``bench/traffic/<t>.json``, read by ``traffic.py``;
 * metric ``<m>``: ``bench/metrics/<m>.py``, whose ``read(run)`` returns the
   value or ``None`` when it finds nothing to read.
+
+A model module provides, for a configuration ``cfg`` (its parsed file):
+
+* ``layers(cfg)``: its synapse layers in order, each a
+  ``bench.roofline.Layer`` from ``dense``, ``conv2d`` or ``sum_pool2d``;
+  the traced window's operations and bytes are counted from them;
+* ``make_weights(cfg, seed)``: the weights, drawn from the seed alone;
+* ``build(cfg, weights)``: the program's packed model for them, built as a
+  user builds it.
+
+Its reference imports nothing of the program and provides
+``forward(cfg, weights, rasters, weight_dtype=None)``, which returns each
+raster's output spikes (bool ``[steps, n_out]``) and the input events each
+layer received over all of them, and ``LOWER_PRECISION``, the control's
+weight type keyed by the configuration's stated one.
 
 The server is built as ``socket_serve`` builds it: ``BucketPolicy.for_mesh``
 over the cell's chips and the server's default queue capacity.
@@ -145,7 +160,7 @@ class Run:
     dispatches: list | None = None    # per dispatch of the window (trace)
     queue_s: np.ndarray | None = None  # per request of the window (trace)
     device: devtrace.DeviceTrace | None = None
-    work: list | None = None          # per layer (ops, bytes) (trace)
+    work: list | None = None    # per layer (ops, bytes, kernel) (trace)
     peaks: dict | None = None
 
 
@@ -327,18 +342,18 @@ def is_correct(checks: dict) -> bool:
 
 
 def window_work(cfg, model_mod, rec: dict, cmp: dict, n_dispatches: int,
-                n_chips: int) -> list[tuple[float, float]]:
-    """Per layer ``(ops, bytes)`` of the traced window: layer 0's input
-    events are counted exactly; deeper layers' are the sample's events per
-    real step times the window's real steps."""
-    sizes = model_mod.layer_sizes(cfg)
+                n_chips: int) -> list[tuple[float, float, str]]:
+    """Per layer ``(ops, bytes, kernel)`` of the traced window, from the
+    model module's ``layers(cfg)``: layer 0's input events are counted
+    exactly; deeper layers' are the sample's events per real step times the
+    window's real steps."""
     rows = len(rec["status"]) * int(cfg["sensor"]["num_steps"])
     events = [float(rec["events_in"].sum())]
     for e in cmp["layer_events"][1:]:
         events.append(e / cmp["steps"] * rows)
-    return [layer_work(n_src, n_dest, ev, rows, n_dispatches, n_chips,
-                       cfg["quant_bits"])
-            for n_src, n_dest, ev in zip(sizes[:-1], sizes[1:], events)]
+    return [(*layer_work(layer, ev, rows, n_dispatches, n_chips,
+                         cfg["quant_bits"]), layer.kernel)
+            for layer, ev in zip(model_mod.layers(cfg), events)]
 
 
 def run_cell(spec: Spec, cell_name: str, seed: int, seconds: float,

@@ -12,12 +12,20 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench.roofline import Layer, dense
 from bench.traffic import input_sensor
 
 
 def layer_sizes(cfg: dict) -> list[int]:
     s = input_sensor(cfg)
     return [2 * s["height"] * s["width"], *cfg["hidden_sizes"]]
+
+
+def layers(cfg: dict) -> list[Layer]:
+    """Every layer is fully connected and runs on the synapse kernel."""
+    sizes = layer_sizes(cfg)
+    return [dense(n_src, n_dest)
+            for n_src, n_dest in zip(sizes[:-1], sizes[1:])]
 
 
 def make_weights(cfg: dict, seed: int) -> list[np.ndarray]:

@@ -50,8 +50,8 @@ def test_device_metric_readers_on_the_synthetic_trace():
     tr = from_planes(planes(), n_chips=2, window_s=0.02)
     run = harness.Run(cell={}, cfg={}, mix={}, n_chips=2,
                       setup_s=1.0, req={}, window=(0.0, 1.0), device=tr,
-                      work=[(2e6, 1e6)], peaks={"flops_per_s": 1e12,
-                                               "hbm_bytes_per_s": 1e9})
+                      work=[(2e6, 1e6, "event_synapse")],
+                      peaks={"flops_per_s": 1e12, "hbm_bytes_per_s": 1e9})
     assert spec.reader("device_idle_pct.sat")(run) == pytest.approx(67.5)
     assert spec.reader("synapse_share_pct.sat")(run) == pytest.approx(
         100 * 0.009 / 0.013)
@@ -62,13 +62,28 @@ def test_device_metric_readers_on_the_synthetic_trace():
         100 * 2e6 / (0.02 * 2 * 1e12))
 
 
+def test_roofline_reads_only_its_kernels_layers_and_mfu_every_layer():
+    spec = harness.Spec()
+    tr = from_planes(planes(), n_chips=2, window_s=0.02)
+    run = harness.Run(cell={}, cfg={}, mix={}, n_chips=2,
+                      setup_s=1.0, req={}, window=(0.0, 1.0), device=tr,
+                      work=[(2e6, 1e6, "event_synapse"),
+                            (4e6, 5e6, "conv_synapse")],
+                      peaks={"flops_per_s": 1e12, "hbm_bytes_per_s": 1e9})
+    # the conv_synapse layer's 5 ms are not the event_synapse ops' work
+    assert spec.reader("synapse_roofline_pct.sat")(run) == pytest.approx(
+        100 / 9)
+    assert spec.reader("mfu.sat")(run) == pytest.approx(
+        100 * 6e6 / (0.02 * 2 * 1e12))
+
+
 def test_readers_find_nothing_without_a_device():
     spec = harness.Spec()
     empty = DeviceTrace(window_s=1.0, chips=[])
     run = harness.Run(cell={}, cfg={}, mix={}, n_chips=1,
                       setup_s=1.0, req={}, window=(0.0, 1.0), device=empty,
-                      work=[(1.0, 1.0)], peaks={"flops_per_s": 1.0,
-                                               "hbm_bytes_per_s": 1.0})
+                      work=[(1.0, 1.0, "event_synapse")],
+                      peaks={"flops_per_s": 1.0, "hbm_bytes_per_s": 1.0})
     for name in ("device_idle_pct.sat", "synapse_share_pct.sat",
                  "synapse_roofline_pct.sat", "mfu.sat"):
         assert spec.reader(name)(run) is None

@@ -5,6 +5,7 @@ timed path is broken underneath."""
 
 import json
 import os
+import pathlib
 import shutil
 import subprocess
 import sys
@@ -153,3 +154,76 @@ def test_config_mix_and_metric_added_as_files_are_picked_up(
     assert "bucket_fill_pct.sat" not in result["metrics"]
     result, _ = run_tiny(spec, "new.closed8", 3, trace=False)
     assert set(result["metrics"]) == {"served_rps", "setup_s"}
+
+
+CONV_FILES = ROOT / "tests/bench/conv_files"
+CONV_CELL = """
+import json, sys, time
+sys.path[:0] = [{root!r}, {src!r}]
+import jax
+from bench import harness
+assert harness.__file__.startswith({root!r}), harness.__file__
+harness.peaks_for = lambda kind: {peaks!r}
+result, checks = harness.run_cell(harness.Spec(harness.ROOT), "conv.closed12",
+                                  {seed}, 1.5, True, time.monotonic(),
+                                  jax.devices())
+print("RESULT " + json.dumps(dict(result, checks=checks)))
+"""
+
+
+def _digests(root):
+    import hashlib
+    return {p.relative_to(root): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in root.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def test_conv_config_added_as_files_is_counted_by_its_geometry(tmp_path):
+    """A convolutional configuration (``tests/bench/conv_files``: a model
+    module, its reference, a configuration, a mix and a metric reader) is
+    added to a copy of the benchmark as new files and BENCHMARK.json entries
+    alone, served there by the copy's own harness, read correct, and counted
+    with the convolution's fan-out, not the dense one."""
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    had = _digests(tmp_path)
+    added = _digests(CONV_FILES)
+    assert added and not set(added) & set(had)
+    shutil.copytree(CONV_FILES, tmp_path, dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    data = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    data["configs"].append({"name": "tiny_conv", "source": "x",
+                            "file": "bench/configs/tiny_conv.json",
+                            "reduced": [], "why": "x"})
+    data["workloads"].append({"name": "conv.closed12", "config": "tiny_conv",
+                              "traffic": "closed12", "chips": 1, "why": "x"})
+    for m in data["end_to_end"]:
+        if m["name"] == "served_rps":
+            m["workloads"].append("conv.closed12")
+    data["per_layer"].append({"name": "conv_fan_out.new", "unit": "1",
+                              "better": "higher", "source": "program_counter",
+                              "layer": "kernels", "moves": "served_rps",
+                              "workloads": ["conv.closed12"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    code = CONV_CELL.format(root=str(tmp_path), src=str(ROOT / "src"),
+                            peaks=STUB_PEAKS, seed=2**31 + 613)
+    proc = subprocess.run([sys.executable, "-c", code], cwd=tmp_path,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT ")]
+    assert line, proc.stderr[-3000:]
+    result = json.loads(line[0][len("RESULT "):])
+    assert result["correct"], result["checks"]
+    assert result["failed"] == 0 and result["attempted"] > 0
+    # 2x6x6 -> 4 channels, 3x3, padding 1: 2,048 (source, destination)
+    # pairs over 72 sources, where the dense count would give all 144
+    fan_out = result["metrics"]["conv_fan_out.new"]["value"]
+    assert fan_out == pytest.approx(2048 / 72, rel=1e-12)
+    # of the files the copy had, only BENCHMARK.json was edited
+    after = _digests(tmp_path)
+    assert [k for k in had if after[k] != had[k]] == [
+        pathlib.Path("BENCHMARK.json")]
